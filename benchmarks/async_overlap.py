@@ -51,7 +51,8 @@ _CHILD = """
     tcfg = ISSGDConfig(batch_size=64, score_batch_size={sb},
                        mode="relaxed", is_cfg=ISConfig(smoothing=1.0),
                        score_shards={w})
-    mesh = jax.make_mesh((ND,), ("data",))
+    from repro.launch.mesh import make_debug_mesh
+    mesh = make_debug_mesh(ND)
     pel = lambda p, b: mlp_pel(p, b, cfg)
     scorer = make_mlp_scorer(cfg, "ghost")
     data = dist.shard_dataset(train.arrays, mesh)
@@ -122,6 +123,8 @@ def _run_child(nd: int, *, n: int, dim: int, sb: int, w: int, steps: int,
 def async_overlap(device_counts=(1, 2, 4, 8), n: int = 4096, dim: int = 96,
                   sb: int = 512, steps: int = 10, swap: int = 1):
     """Benchmark-harness entry: (rows, summary)."""
+    from benchmarks.common import require_forced_host_cpu
+    require_forced_host_cpu("async_overlap")
     w = max(device_counts)  # same logical decomposition at every size
     rows = []
     for nd in device_counts:

@@ -3,6 +3,7 @@ mirroring the paper's §5 experiments at CPU scale (same algorithm, smaller
 MLP/data so each figure runs in ~a minute)."""
 from __future__ import annotations
 
+import os
 import time
 
 import jax
@@ -21,6 +22,18 @@ from repro.optim import sgd
 CFG = MLPConfig(name="mlp_svhn_bench", input_dim=96, hidden=(256, 256),
                 num_classes=10)
 N_TRAIN = 8192
+
+
+def require_forced_host_cpu(bench: str) -> None:
+    """Refuse to start forced-host-device children off the CPU.
+
+    The scaling harnesses time XLA host "devices" in child processes. On a
+    chip the parent or a sibling holds the device, so the children would
+    fail or hang, and host devices are not what a chip run measures."""
+    if "cpu" not in os.environ.get("JAX_PLATFORMS", "").split(","):
+        raise RuntimeError(
+            f"{bench} times forced CPU host devices and runs only with "
+            f"JAX_PLATFORMS=cpu; on a TPU use chip_smoke.py instead")
 
 
 def time_fn(fn, *args, reps: int = 3) -> float:

@@ -4,6 +4,8 @@ under benchmarks/results/.
 
   PYTHONPATH=src python -m benchmarks.run            # everything
   PYTHONPATH=src python -m benchmarks.run --only fig4,scoring
+
+Exits non-zero when any selected benchmark errored or --only matched none.
 """
 from __future__ import annotations
 
@@ -128,11 +130,9 @@ def main() -> None:
     ap.add_argument("--bench-json", default="",
                     help="also write the summaries to this path (CI "
                     "uploads it as the BENCH_* artifact)")
-    ap.add_argument("--strict", action="store_true",
-                    help="exit nonzero if any selected benchmark errored "
-                    "or the --only filter matched nothing (CI gate; "
-                    "default keeps the harness running)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     only = [s.strip() for s in args.only.split(",") if s.strip()]
 
     print("name,us_per_call,derived")
@@ -144,7 +144,7 @@ def main() -> None:
         t0 = time.time()
         try:
             rows, summary = fn()
-        except Exception as e:  # keep the harness running
+        except Exception as e:  # run the rest, then exit non-zero
             print(f"{name},ERROR,{e!r}")
             errors.append(name)
             continue
@@ -160,9 +160,9 @@ def main() -> None:
     if args.bench_json:
         with open(args.bench_json, "w") as f:
             json.dump(all_summaries, f, indent=2, default=str)
-    if args.strict and (errors or not all_summaries):
-        print(f"STRICT: {len(errors)} errored, "
-              f"{len(all_summaries)} succeeded", file=sys.stderr)
+    if errors or not all_summaries:
+        print(f"{len(errors)} errored, {len(all_summaries)} succeeded",
+              file=sys.stderr)
         sys.exit(1)
 
 

@@ -189,6 +189,8 @@ def sharded_scaling(device_counts=(1, 2, 4), n: int = 4096, dim: int = 96,
     scoring through the model-axis-aware forward) and reports the
     sequence-parallel step time next to the replicated-norm one, plus
     the per-device norm-segment activation bytes both ways."""
+    from benchmarks.common import require_forced_host_cpu
+    require_forced_host_cpu("sharded_scaling")
     w = max(device_counts)  # same logical decomposition at every size
     rows = []
     for mp in mp_counts:

@@ -1,9 +1,9 @@
 """Jitted public wrappers around the Pallas kernels.
 
 Responsibilities:
-  * interpret-mode selection: on CPU backends the kernels run with
+  * interpret-mode selection: on the CPU backend the kernels run with
     interpret=True (Python emulation, used for validation); on TPU they
-    lower to Mosaic.
+    lower to Mosaic; any other backend is an error.
   * padding to kernel-friendly shapes (done inside the kernel modules).
   * algorithm selection for the ghost norm: the blocked Gram kernel costs
     O(S²(din+dout)) while the direct per-example einsum costs
@@ -26,7 +26,15 @@ from repro.kernels.selective_scan import selective_scan as _selective_scan
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Pallas interpret mode on the CPU (validation), Mosaic on the TPU.
+    Any other backend is refused rather than silently emulated."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"Pallas kernels run on 'tpu' (Mosaic) or 'cpu' "
+                       f"(interpret mode), not on backend {backend!r}")
 
 
 # ------------------------------------------------------------------ Prop. 1

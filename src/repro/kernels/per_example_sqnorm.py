@@ -122,7 +122,7 @@ def _multi_kernel(x_ref, d_ref, out_ref, xs_acc, ds_acc, *, nkx: int,
         res = xs_acc[...] * ds_acc[...]
         if with_bias:
             res = res + ds_acc[...]
-        out_ref[0] = res
+        out_ref[...] = res.reshape(out_ref.shape)
 
 
 def per_example_sqnorm_multi(
@@ -143,7 +143,7 @@ def per_example_sqnorm_multi(
     axis; the grid is (batch_blocks, taps, feature_blocks) — ONE sweep
     over all taps' operands instead of T kernel launches.  Zero padding
     is exact for sums of squares and the per-block reduction expressions
-    match the single-tap kernel, so each tap's row of the (T, B) kernel
+    match the single-tap kernel, so each tap's row of the (T, 1, B) kernel
     output is bitwise-equal to its single-tap launch; the wrapper then
     chains the tap adds in order, making the result BITWISE-identical to
     summing T single-tap launches (same block sizes) in the same order."""
@@ -179,15 +179,17 @@ def per_example_sqnorm_multi(
             pl.BlockSpec((1, bb, block_k),
                          lambda i, t, k: (t, i, jnp.minimum(k, nkd - 1))),
         ],
-        out_specs=pl.BlockSpec((1, bb), lambda i, t, k: (t, i)),
-        out_shape=jax.ShapeDtypeStruct((n_taps, b + pad_b), jnp.float32),
+        # the unit middle dim keeps the block's last two dims (1, bb) legal
+        # for Mosaic: a (1, bb) block over (T, B) is refused for T > 1
+        out_specs=pl.BlockSpec((1, 1, bb), lambda i, t, k: (t, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((n_taps, 1, b + pad_b), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((bb,), jnp.float32),
             pltpu.VMEM((bb,), jnp.float32),
         ],
         interpret=interpret,
     )(xstk, dstk)
-    res = out[0]
+    res = out[0, 0]
     for t in range(1, n_taps):
-        res = res + out[t]
+        res = res + out[t, 0]
     return res[:b]

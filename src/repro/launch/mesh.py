@@ -6,6 +6,14 @@ touch jax device state (the dry-run sets XLA_FLAGS before any jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """`jax.make_mesh` with Auto axes: JAX 0.9 defaults to Explicit axes,
+    under which `with_sharding_constraint` and the mesh decode path's
+    implicit resharding are refused."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -13,7 +21,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     "pod" axis (data-parallel across the slower inter-pod links)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n: int | None = None, model: int = 1):
@@ -22,9 +30,9 @@ def make_debug_mesh(n: int | None = None, model: int = 1):
     the production mesh's trailing tensor-parallel axis."""
     if model > 1:
         n = n or len(jax.devices()) // model
-        return jax.make_mesh((n, model), ("data", "model"))
+        return _auto_mesh((n, model), ("data", "model"))
     n = n or len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return _auto_mesh((n,), ("data",))
 
 
 # v5e hardware constants for the roofline analysis (per chip)

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import init_transformer
 from repro.serving.engine import decode_step, prefill
 
@@ -28,6 +29,7 @@ def main():
     ap.add_argument("--kernel", default="ref", choices=["ref", "pallas"])
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     max_len = args.max_len or (args.prompt_len + args.steps)

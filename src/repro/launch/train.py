@@ -11,10 +11,11 @@ path, smaller mesh), e.g.:
 Sharded execution (`core/distributed.py`): `--mesh N` runs the step under
 shard_map on an N-device data mesh — dataset, WeightStore, and the scoring
 fan-out sharded over the data axis, hierarchical two-stage sampling, no
-full-table gathers.  On CPU, N host devices are forced via XLA_FLAGS
-automatically, so the whole path works without a pod:
+full-table gathers.  With JAX_PLATFORMS=cpu, N host devices are forced via
+XLA_FLAGS automatically, so the whole path works without a pod:
 
-  PYTHONPATH=src python -m repro.launch.train --arch mlp_svhn --smoke --mesh 4
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.train \
+      --arch mlp_svhn --smoke --mesh 4
 
 Streaming data plane (`data/streaming.py`): `--stream` keeps the dataset
 host-resident in chunked form and feeds the devices a bounded,
@@ -52,16 +53,17 @@ import time
 
 
 def _force_host_devices(n: int) -> None:
-    """Force n host devices on CPU backends.  Must run before the jax
-    backend initializes (importing jax alone does not initialize it)."""
+    """Force n host devices when ``JAX_PLATFORMS`` names ``cpu``.  Must run
+    before the jax backend initializes (importing jax alone does not
+    initialize it).  Anywhere else the mesh takes the real devices, so a
+    TPU that failed to start is an error, not a run on host devices."""
     if n <= 1:
         return
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" in flags:
         return  # caller already chose a device count
-    platforms = os.environ.get("JAX_PLATFORMS", "cpu")
-    if "cpu" not in platforms:
-        return  # real accelerators: use them as-is
+    if "cpu" not in os.environ.get("JAX_PLATFORMS", "").split(","):
+        return
     os.environ["XLA_FLAGS"] = (
         flags + f" --xla_force_host_platform_device_count={n}").strip()
 
@@ -76,6 +78,7 @@ from repro.core.issgd import ISSGDConfig, init_train_state, make_train_step
 from repro.core.scorer import make_lm_scorer, make_mlp_scorer
 from repro.core.strategies import PROPOSALS, make_proposal
 from repro.data import make_svhn_like, make_token_dataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import sgd
 
 
@@ -229,7 +232,10 @@ docs/ARCHITECTURE.md):
 """
 
 
-def main():
+def main(argv=None, on_step=None):
+    """Parse ``argv`` (default ``sys.argv[1:]``), train, and return the
+    final ``(state, data)``; ``on_step(i, state, metrics)``, when given,
+    sees every step's outputs (``chip_smoke.py`` drives the trainer so)."""
     ap = argparse.ArgumentParser(
         epilog=_FLAG_RULES,
         formatter_class=argparse.RawDescriptionHelpFormatter)
@@ -290,8 +296,8 @@ def main():
                     "scoring shard)")
     ap.add_argument("--mesh", type=int, default=0,
                     help="run the sharded step on an N-device data mesh "
-                    "(0 = single-device path); on CPU, N host devices are "
-                    "forced automatically")
+                    "(0 = single-device path); with JAX_PLATFORMS=cpu, N "
+                    "host devices are forced automatically")
     ap.add_argument("--model-parallel", type=int, default=1,
                     help="tensor-shard params + optimizer state over a "
                     "trailing M-device model axis (composes with --mesh/"
@@ -392,12 +398,21 @@ def main():
                     help="block on each phase's outputs inside its span "
                     "(true per-phase wall-clock; serializes the async "
                     "scoring/master overlap — off by default)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     mp = max(args.model_parallel, 1)
     dp = max(args.mesh, 1)
     use_mesh = args.mesh > 0 or mp > 1
     validate_flags(ap, args, mp)
     _force_host_devices(dp * mp if use_mesh else args.mesh)
+    enable_compile_cache()
+    devices = jax.devices()
+    print(f"devices: {devices[0].platform} {devices[0].device_kind} "
+          f"x{len(devices)}", flush=True)
+    if use_mesh and len(devices) < dp * mp:
+        ap.error(f"--mesh {dp} x --model-parallel {mp} needs {dp * mp} "
+                 f"devices, found {len(devices)} {devices[0].platform} "
+                 f"device(s); on the CPU set JAX_PLATFORMS=cpu to force "
+                 f"host devices")
     model_axes = ("model",) if mp > 1 else ()
     seq_shard = mp > 1 and (args.sequence_parallel is None
                             or args.sequence_parallel)
@@ -674,6 +689,8 @@ def main():
                 state, m, mon = out
             else:
                 state, m = out
+        if on_step is not None:
+            on_step(i, state, m)
         if serve is not None:
             # finished traffic lands in the store between steps, once the
             # tick's training dispatches have retired (donation safety)
@@ -762,6 +779,7 @@ def main():
                    served_dropped=int(serve.ingest.dropped))
     sink.emit("run_end", step=args.steps - 1, **end)
     sink.close()
+    return state, data
 
 
 if __name__ == "__main__":

@@ -136,7 +136,10 @@ def test_two_stage_sampler_unbiased():
     mesh-size-1 special case exercises the same arithmetic)."""
     from repro.core.sampler import two_stage_sample
 
-    n, m = 1024, 400_000
+    # 8M draws: at 400k the rtol=5e-3 bound below sat at 0.89σ of the
+    # estimator and the TV bound at its own expectation (≈0.019), so RNG
+    # stream changes flipped them; at 8M both are ≥ 4σ from their bounds
+    n, m = 1024, 8_000_000
     w = (jnp.arange(n, dtype=jnp.float32) % 23) + 0.25
     idx = np.asarray(two_stage_sample(jax.random.key(5), w, m,
                                       shards_per_device=8))
