@@ -275,30 +275,32 @@ def make_scoring_pass(
     axes = tuple(axes)
 
     def scoring_pass(score_params, store: WeightStore, step, data):
-        _, n_dev = axis_info(axes)
-        n_local = store.weights.shape[0]
-        w_loc, n_w, sb_w = _resolve_shards(cfg, n, sb, n_local, n_dev)
-        score_idx = _score_slice(step, w_loc, n_w, sb_w)
-        score_batch = constrain_batch(
-            data if streaming else gather_batch(data, score_idx))
-        fresh_scores = scorer(score_params, score_batch)
-        # stale view of the slice BEFORE the write (for eq. 9 monitor)
-        pre_proposal = read_proposal(store, step, is_cfg)
-        stale_slice = pre_proposal[score_idx]
-        # reserved serving-capacity rows (scored_at == EMPTY) stay inert:
-        # their scores are forced to 0 and their EMPTY stamp survives the
-        # write, so un-ingested rows never gain proposal mass.  With no
-        # reserved rows in the slice this is the identity dataflow.
-        from repro.core.weight_store import EMPTY
-        live = store.scored_at[score_idx] > EMPTY
-        fresh_scores = jnp.where(live, fresh_scores,
-                                 jnp.zeros_like(fresh_scores))
-        stamp = jnp.where(live,
-                          jnp.broadcast_to(jnp.asarray(step, jnp.int32),
-                                           live.shape),
-                          jnp.asarray(EMPTY, jnp.int32))
-        new_store = write_scores(store, score_idx, fresh_scores, stamp)
-        return new_store, fresh_scores, stale_slice
+        with jax.named_scope("issgd.score"):
+            _, n_dev = axis_info(axes)
+            n_local = store.weights.shape[0]
+            w_loc, n_w, sb_w = _resolve_shards(cfg, n, sb, n_local, n_dev)
+            score_idx = _score_slice(step, w_loc, n_w, sb_w)
+            score_batch = constrain_batch(
+                data if streaming else gather_batch(data, score_idx))
+            fresh_scores = scorer(score_params, score_batch)
+            # stale view of the slice BEFORE the write (for eq. 9 monitor)
+            pre_proposal = read_proposal(store, step, is_cfg)
+            stale_slice = pre_proposal[score_idx]
+            # reserved serving-capacity rows (scored_at == EMPTY) stay
+            # inert: their scores are forced to 0 and their EMPTY stamp
+            # survives the write, so un-ingested rows never gain proposal
+            # mass.  With no reserved rows in the slice this is the
+            # identity dataflow.
+            from repro.core.weight_store import EMPTY
+            live = store.scored_at[score_idx] > EMPTY
+            fresh_scores = jnp.where(live, fresh_scores,
+                                     jnp.zeros_like(fresh_scores))
+            stamp = jnp.where(live,
+                              jnp.broadcast_to(jnp.asarray(step, jnp.int32),
+                                               live.shape),
+                              jnp.asarray(EMPTY, jnp.int32))
+            new_store = write_scores(store, score_idx, fresh_scores, stamp)
+            return new_store, fresh_scores, stale_slice
 
     return scoring_pass
 
@@ -392,104 +394,113 @@ def make_master_pass(
 
         # ---- 2. master reads the proposal (B.1 + B.3 + optional TTL
         # decay, dequantized for non-f32 tables), shard-local -----------------
-        proposal = read_sampling_proposal(store, step, cfg, n_w)
-        sum_w = psum(jnp.sum(proposal), axes)
-        mean_weight = sum_w / n
+        with jax.named_scope("issgd.proposal"):
+            proposal = read_sampling_proposal(store, step, cfg, n_w)
+            sum_w = psum(jnp.sum(proposal), axes)
+            mean_weight = sum_w / n
         if monitors:
             from repro.telemetry.monitors import proposal_monitors
             # over the proposal actually sampled from, BEFORE this step's
             # writes (in async mode `store` is the lagged read_buf, so the
             # staleness monitor observes exactly L(t))
-            mon = proposal_monitors(store, proposal, step, axes, n,
-                                    monitors, sum_w=sum_w)
+            with jax.named_scope("issgd.monitors"):
+                mon = proposal_monitors(store, proposal, step, axes, n,
+                                        monitors, sum_w=sum_w)
 
         # ---- 3. compose the minibatch (two-stage sample + one-owner gather) --
-        if cfg.mode == "uniform":
-            idx = jax.random.randint(k_sample, (cfg.batch_size,), 0, n)
-            scales = jnp.ones((cfg.batch_size,), jnp.float32)
-        elif gated:
-            # both draws from the same k_sample (pure functions of the
-            # key), selected by the controller's gate: a closed gate IS
-            # the uniform branch above, bit-for-bit
-            idx_u = jax.random.randint(k_sample, (cfg.batch_size,), 0, n)
-            idx_is = two_stage_sample(k_sample, proposal, cfg.batch_size,
-                                      axes=axes, shards_per_device=w_loc,
-                                      block_sums=stage1_block_sums(
-                                          proposal, w_loc, cfg))
-            idx = jnp.where(use_is, idx_is, idx_u)
-            sampled_w = gather_rows(proposal, idx, axes)
-            scales = jnp.where(use_is,
-                               is_loss_scale(sampled_w, mean_weight),
-                               jnp.ones((cfg.batch_size,), jnp.float32))
-        else:
-            idx = two_stage_sample(k_sample, proposal, cfg.batch_size,
-                                   axes=axes, shards_per_device=w_loc,
-                                   block_sums=stage1_block_sums(
-                                       proposal, w_loc, cfg))
-            sampled_w = gather_rows(proposal, idx, axes)
-            scales = is_loss_scale(sampled_w, mean_weight)
-        batch = constrain_batch(data if streaming
-                                else gather_rows(data, idx, axes))
+        with jax.named_scope("issgd.sample"):
+            if cfg.mode == "uniform":
+                idx = jax.random.randint(k_sample, (cfg.batch_size,), 0, n)
+                scales = jnp.ones((cfg.batch_size,), jnp.float32)
+            elif gated:
+                # both draws from the same k_sample (pure functions of the
+                # key), selected by the controller's gate: a closed gate IS
+                # the uniform branch above, bit-for-bit
+                idx_u = jax.random.randint(k_sample, (cfg.batch_size,), 0,
+                                           n)
+                idx_is = two_stage_sample(
+                    k_sample, proposal, cfg.batch_size, axes=axes,
+                    shards_per_device=w_loc,
+                    block_sums=stage1_block_sums(proposal, w_loc, cfg))
+                idx = jnp.where(use_is, idx_is, idx_u)
+                sampled_w = gather_rows(proposal, idx, axes)
+                scales = jnp.where(use_is,
+                                   is_loss_scale(sampled_w, mean_weight),
+                                   jnp.ones((cfg.batch_size,), jnp.float32))
+            else:
+                idx = two_stage_sample(k_sample, proposal, cfg.batch_size,
+                                       axes=axes, shards_per_device=w_loc,
+                                       block_sums=stage1_block_sums(
+                                           proposal, w_loc, cfg))
+                sampled_w = gather_rows(proposal, idx, axes)
+                scales = is_loss_scale(sampled_w, mean_weight)
+            batch = constrain_batch(data if streaming
+                                    else gather_rows(data, idx, axes))
 
         # ---- 4. unbiased IS-scaled update (§4.1) ----------------------------
         # The gathered minibatch is replicated; every device computes the
         # identical master update (the paper's single master, SPMD-style) —
         # the parallelism win is the scoring fan-out above, which is the
         # dominant cost (score_batch_size ≫ batch_size).
-        def loss_fn(params):
-            if cfg.mode == "fused":
-                losses, scores = fused_score(params, batch)
-                scores = jax.lax.stop_gradient(scores)
-            else:
-                losses, scores = per_example_loss(params, batch), None
-            loss = jnp.mean(losses * scales)
-            if aux_loss is not None:
-                loss = loss + aux_loss(params, batch)
-            return loss, scores
+        with jax.named_scope("issgd.update"):
+            def loss_fn(params):
+                if cfg.mode == "fused":
+                    losses, scores = fused_score(params, batch)
+                    scores = jax.lax.stop_gradient(scores)
+                else:
+                    losses, scores = per_example_loss(params, batch), None
+                loss = jnp.mean(losses * scales)
+                if aux_loss is not None:
+                    loss = loss + aux_loss(params, batch)
+                return loss, scores
 
-        (loss, batch_scores), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params)
-        if cfg.mode == "fused":
-            # zero-cost refresh for the examples just trained on.
-            # NOTE: the fig-4 monitors below are then computed on an
-            # importance-SAMPLED slice rather than a uniform one, so
-            # trace_stale is biased upward (high-weight examples are
-            # over-represented); use the probe step's uniform slices for
-            # faithful monitoring in fused mode.
-            fresh_scores = batch_scores
-            stale_slice = sampled_w  # proposal at idx, already gathered
-            store = write_scores_global(store, idx, batch_scores, step, axes)
-        gnorm = _grad_global_norm(grads, model_axes, param_pspecs)
-        if cfg.grad_clip > 0:
-            from repro.optim import clip_by_global_norm
-            # clip against the model-axis-aware norm computed above
-            grads, _ = clip_by_global_norm(grads, cfg.grad_clip, norm=gnorm)
-        new_params, opt_state = optimizer.update(grads, opt_state,
-                                                 params, step)
+            (loss, batch_scores), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params)
+            if cfg.mode == "fused":
+                # zero-cost refresh for the examples just trained on.
+                # NOTE: the fig-4 monitors below are then computed on an
+                # importance-SAMPLED slice rather than a uniform one, so
+                # trace_stale is biased upward (high-weight examples are
+                # over-represented); use the probe step's uniform slices
+                # for faithful monitoring in fused mode.
+                fresh_scores = batch_scores
+                stale_slice = sampled_w  # proposal at idx, already gathered
+                store = write_scores_global(store, idx, batch_scores, step,
+                                            axes)
+            gnorm = _grad_global_norm(grads, model_axes, param_pspecs)
+            if cfg.grad_clip > 0:
+                from repro.optim import clip_by_global_norm
+                # clip against the model-axis-aware norm computed above
+                grads, _ = clip_by_global_norm(grads, cfg.grad_clip,
+                                               norm=gnorm)
+            new_params, opt_state = optimizer.update(grads, opt_state,
+                                                     params, step)
 
         # ---- 5. parameter push to the workers every K steps ------------------
-        if cfg.mode == "exact":
-            stale_params = new_params
-        else:
-            push = (step + 1) % cfg.refresh_every == 0
-            stale_params = jax.tree.map(
-                lambda new, old: jnp.where(push, new, old),
-                new_params, stale_params)
+        with jax.named_scope("issgd.push"):
+            if cfg.mode == "exact":
+                stale_params = new_params
+            else:
+                push = (step + 1) % cfg.refresh_every == 0
+                stale_params = jax.tree.map(
+                    lambda new, old: jnp.where(push, new, old),
+                    new_params, stale_params)
 
         # ---- 6. paper fig. 4 monitors over the scored slice ------------------
         # ||g_TRUE||² upper bound (B.2): the minibatch gradient norm
-        if cfg.mode == "fused":
-            # replicated minibatch slice: no psum (it would double-count)
-            traces = variance.trace_sigma_all(fresh_scores, stale_slice)
-        elif fresh_scores is None:
-            # async pipeline: the scoring step owns the trace monitors
-            nan = jnp.full((), jnp.nan, jnp.float32)
-            traces = variance.TraceSigma(ideal=nan, stale=nan, unif=nan)
-        else:
-            traces = variance.trace_sigma_all_dist(fresh_scores, stale_slice,
-                                                   axes, n_total=sb)
-        sum_w2 = psum(jnp.sum(jnp.square(proposal)), axes)
-        ess = effective_sample_size(proposal, s1=sum_w, s2=sum_w2) / n
+        with jax.named_scope("issgd.monitors"):
+            if cfg.mode == "fused":
+                # replicated minibatch slice: no psum (it would double-count)
+                traces = variance.trace_sigma_all(fresh_scores, stale_slice)
+            elif fresh_scores is None:
+                # async pipeline: the scoring step owns the trace monitors
+                nan = jnp.full((), jnp.nan, jnp.float32)
+                traces = variance.TraceSigma(ideal=nan, stale=nan, unif=nan)
+            else:
+                traces = variance.trace_sigma_all_dist(
+                    fresh_scores, stale_slice, axes, n_total=sb)
+            sum_w2 = psum(jnp.sum(jnp.square(proposal)), axes)
+            ess = effective_sample_size(proposal, s1=sum_w, s2=sum_w2) / n
 
         metrics = StepMetrics(
             loss=loss, grad_norm=gnorm,

@@ -100,6 +100,7 @@ def ghost_norm(
     out = pl.pallas_call(
         functools.partial(_kernel, nkx=nkx, nkd=nkd, symmetric=symmetric),
         grid=grid,
+        name="ghost_norm",
         in_specs=[
             pl.BlockSpec((1, bs, block_k),
                          lambda bi, i, j, k: (bi, i, jnp.minimum(k, nkx - 1))),

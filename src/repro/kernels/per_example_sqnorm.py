@@ -76,6 +76,7 @@ def per_example_sqnorm(
     out = pl.pallas_call(
         functools.partial(_kernel, nkx=nkx, nkd=nkd, with_bias=with_bias),
         grid=grid,
+        name="per_example_sqnorm",
         in_specs=[
             pl.BlockSpec((bb, block_k), lambda i, k: (i, jnp.minimum(k, nkx - 1))),
             pl.BlockSpec((bb, block_k), lambda i, k: (i, jnp.minimum(k, nkd - 1))),
@@ -173,6 +174,7 @@ def per_example_sqnorm_multi(
         functools.partial(_multi_kernel, nkx=nkx, nkd=nkd,
                           with_bias=with_bias),
         grid=grid,
+        name="per_example_sqnorm_multi",
         in_specs=[
             pl.BlockSpec((1, bb, block_k),
                          lambda i, t, k: (t, i, jnp.minimum(k, nkx - 1))),

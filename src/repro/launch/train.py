@@ -88,6 +88,16 @@ def _proposal_name(args) -> str:
     return args.proposal_strategy or args.strategy
 
 
+def _profile_options() -> "jax.profiler.ProfileOptions":
+    """`--profile-dir`'s capture options: host annotations and runtime
+    events, no Python tracer (it would slow the very host loop the trace
+    shows) and no HLO protos, as the benchmark traces its steps."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.enable_hlo_proto = False
+    return opts
+
+
 def build_mlp(args, model_axes=()):
     from repro.configs.mlp_svhn import CONFIG, smoke
     from repro.models.mlp import (init_mlp_classifier, mlp_specs,
@@ -394,10 +404,6 @@ def main(argv=None, on_step=None):
     ap.add_argument("--profile-steps", default="2:2",
                     help="profiler capture window as START:COUNT train "
                     "steps (default 2:2 — skip compile, grab two steps)")
-    ap.add_argument("--telemetry-blocking", action="store_true",
-                    help="block on each phase's outputs inside its span "
-                    "(true per-phase wall-clock; serializes the async "
-                    "scoring/master overlap — off by default)")
     args = ap.parse_args(argv)
     mp = max(args.model_parallel, 1)
     dp = max(args.mesh, 1)
@@ -452,8 +458,7 @@ def main(argv=None, on_step=None):
         # the tap is truthy even over a NullSink, so the metrics/span
         # records the controller feeds on keep flowing file or no file
         sink = ctl.attach(sink)
-    tel = Telemetry(sink, every=args.metrics_every or args.log_every,
-                    blocking=args.telemetry_blocking)
+    tel = Telemetry(sink, every=args.metrics_every or args.log_every)
 
     if args.arch == "mlp_svhn":
         params, train, pel, scorer, param_specs = build_mlp(args, model_axes)
@@ -673,76 +678,83 @@ def main(argv=None, on_step=None):
     profiling = False
     for i in range(args.steps):
         if args.profile_dir and i == prof_start:
-            jax.profiler.start_trace(args.profile_dir)
+            jax.profiler.start_trace(args.profile_dir,
+                                     profiler_options=_profile_options())
             profiling = True
             sink.emit("profile", step=i, action="start",
                       dir=args.profile_dir)
-        mon = None
-        if pipe is not None:
-            state, m = pipe.step(state, data)
-            mon = pipe.last_monitors
-        else:
-            sargs = ((state, data, ctl.gate()) if step_gated
-                     else (state, data))
-            out = tel.timed("train.step", step, *sargs, step=i)
-            if step_monitors:
-                state, m, mon = out
+        with tel.step(i):
+            mon = None
+            if pipe is not None:
+                state, m = pipe.step(state, data)
+                mon = pipe.last_monitors
             else:
-                state, m = out
-        if on_step is not None:
-            on_step(i, state, m)
-        if serve is not None:
-            # finished traffic lands in the store between steps, once the
-            # tick's training dispatches have retired (donation safety)
-            state = serve.ingest_into(state)
-        if probe is not None and i % args.probe_every == 0:
-            state = probe(state, data)
+                sargs = ((state, data, ctl.gate()) if step_gated
+                         else (state, data))
+                out = tel.timed("train.dispatch", step, *sargs, step=i)
+                if step_monitors:
+                    state, m, mon = out
+                else:
+                    state, m = out
+            if on_step is not None:
+                with tel.span("train.callback", step=i):
+                    on_step(i, state, m)
+            if serve is not None:
+                # finished traffic lands in the store between steps, once the
+                # tick's training dispatches have retired (donation safety)
+                state = serve.ingest_into(state)
+            if probe is not None and i % args.probe_every == 0:
+                state = tel.timed("train.probe", probe, state, data, step=i)
+            log_now = i % args.log_every == 0 or i == args.steps - 1
+            emit_now = bool(sink) and (tel.due(i) or i == args.steps - 1)
+            if log_now or emit_now:
+                # ONE forced transfer for everything this step logs —
+                # per-field float() calls would each block the dispatch
+                # queue separately
+                vals, mon_vals = tel.timed(
+                    "train.log_sync", jax.device_get,
+                    ((m.loss, m.grad_norm, m.trace_ideal, m.trace_stale,
+                      m.trace_unif, m.ess_frac), mon), step=i)
+                rec = {"step": i, "loss": float(vals[0]),
+                       "grad_norm": float(vals[1]),
+                       "trace_ideal": float(vals[2]),
+                       "trace_stale": float(vals[3]),
+                       "trace_unif": float(vals[4]),
+                       "ess_frac": float(vals[5]),
+                       "elapsed_s": round(time.time() - t0, 2)}
+                if plane is not None:
+                    rec["stream_hit_rate"] = round(plane.stats.hit_rate, 4)
+                if serve is not None:
+                    rec["served_rows"] = int(serve.ingest.ingested)
+                if log_now:
+                    history.append(rec)
+                    print(f"step {i:5d} loss {rec['loss']:.4f} "
+                          f"√TrΣ ideal/stale/unif = "
+                          f"{rec['trace_ideal']:.3f}/"
+                          f"{rec['trace_stale']:.3f}/"
+                          f"{rec['trace_unif']:.3f} "
+                          f"ess {rec['ess_frac']:.3f}", flush=True)
+                if emit_now:
+                    sink.emit("metrics", step=i,
+                              **{k: v for k, v in rec.items() if k != "step"})
+                    if mon_vals is not None:
+                        sink.emit("monitors", step=i,
+                                  **{k: v for k, v in mon_vals.items()})
+            if ctl is not None:
+                # after the step's metrics have been folded into the window
+                d = ctl.maybe_decide(i)
+                if d is not None:
+                    if pipe is not None:
+                        pipe.swap_every = d.swap_every
+                    print(f"controller: step {i} use_is={d.use_is} "
+                          f"swap_every={d.swap_every} reason={d.reason}",
+                          flush=True)
         if profiling and i == prof_start + prof_count - 1:
             # retire the window's dispatches before closing the trace
             jax.block_until_ready(state.params)
             jax.profiler.stop_trace()
             profiling = False
             sink.emit("profile", step=i, action="stop")
-        log_now = i % args.log_every == 0 or i == args.steps - 1
-        emit_now = bool(sink) and (tel.due(i) or i == args.steps - 1)
-        if log_now or emit_now:
-            # ONE forced transfer for everything this step logs — per-field
-            # float() calls would each block the dispatch queue separately
-            vals, mon_vals = jax.device_get(
-                ((m.loss, m.grad_norm, m.trace_ideal, m.trace_stale,
-                  m.trace_unif, m.ess_frac), mon))
-            rec = {"step": i, "loss": float(vals[0]),
-                   "grad_norm": float(vals[1]),
-                   "trace_ideal": float(vals[2]),
-                   "trace_stale": float(vals[3]),
-                   "trace_unif": float(vals[4]),
-                   "ess_frac": float(vals[5]),
-                   "elapsed_s": round(time.time() - t0, 2)}
-            if plane is not None:
-                rec["stream_hit_rate"] = round(plane.stats.hit_rate, 4)
-            if serve is not None:
-                rec["served_rows"] = int(serve.ingest.ingested)
-            if log_now:
-                history.append(rec)
-                print(f"step {i:5d} loss {rec['loss']:.4f} "
-                      f"√TrΣ ideal/stale/unif = {rec['trace_ideal']:.3f}/"
-                      f"{rec['trace_stale']:.3f}/{rec['trace_unif']:.3f} "
-                      f"ess {rec['ess_frac']:.3f}", flush=True)
-            if emit_now:
-                sink.emit("metrics", step=i,
-                          **{k: v for k, v in rec.items() if k != "step"})
-                if mon_vals is not None:
-                    sink.emit("monitors", step=i,
-                              **{k: v for k, v in mon_vals.items()})
-        if ctl is not None:
-            # after the step's metrics have been folded into the window
-            d = ctl.maybe_decide(i)
-            if d is not None:
-                if pipe is not None:
-                    pipe.swap_every = d.swap_every
-                print(f"controller: step {i} use_is={d.use_is} "
-                      f"swap_every={d.swap_every} reason={d.reason}",
-                      flush=True)
     if profiling:   # window ran past the end of the run
         jax.block_until_ready(state.params)
         jax.profiler.stop_trace()

@@ -8,15 +8,16 @@ Three planes, one package (docs/ARCHITECTURE.md §8):
     identity code path (HLO-pinned), on never perturbs the trajectory;
   * **events** (`events.py`) — a schema-versioned JSONL sink for spans,
     counters, and per-step metrics records, host-side and buffered;
-  * **spans** (`spans.py`) — phase wall-clock timing with a non-blocking
-    default so instrumenting an async run never re-serializes the
-    scoring/master overlap.
+  * **spans** (`spans.py`) — named host phases: always a profiler
+    annotation (on the device trace's clock), plus a wall-clock record
+    when a sink is open; non-blocking, so instrumenting an async run
+    never re-serializes the scoring/master overlap.
 
 `Telemetry` is the facade the host drivers (`AsyncPipeline`,
-`StreamedISSGD`, `ServeLoop`, `launch/train.py`) carry: sink + span
-timing + the periodic-counter cadence.  `Telemetry.null()` is the
-always-available no-op instance, so pipeline code has exactly one path
-whether telemetry is on or off.
+`StreamedISSGD`, `ServeLoop`, `launch/train.py`) carry: sink + spans +
+the periodic-counter cadence.  `Telemetry.null()` is the always-available
+instance with nothing to write into; its spans still annotate, so
+pipeline code has exactly one path whether telemetry is on or off.
 """
 from __future__ import annotations
 
@@ -31,22 +32,17 @@ __all__ = ["EventSink", "NullSink", "MonitorSet", "MONITOR_NAMES",
 
 
 class Telemetry:
-    """Facade handed to the host drivers: an event sink, span timing, and
-    the cadence at which periodic counters fire.
-
-    ``blocking=False`` (default) keeps every span dispatch-only — the
-    async overlap contract; ``blocking=True`` waits on each timed call's
-    outputs for true per-phase wall-clock (sync/profiling runs).
-    """
+    """Facade handed to the host drivers: an event sink, spans, and the
+    cadence at which periodic counters fire.  Every span is dispatch-only
+    (the async overlap contract) and annotates the profiler's trace."""
 
     _null = None
 
-    def __init__(self, sink, every: int = 10, blocking: bool = False):
+    def __init__(self, sink, every: int = 10):
         if every < 1:
             raise ValueError(f"telemetry cadence must be >= 1, got {every}")
         self.sink = sink
         self.every = int(every)
-        self.blocking = bool(blocking)
 
     @classmethod
     def null(cls) -> "Telemetry":
@@ -60,16 +56,17 @@ class Telemetry:
 
     def timed(self, name: str, fn: Callable, *args,
               step: Optional[int] = None):
-        """Run ``fn(*args)`` inside a span named `name` (see spans.timed);
-        blocking per this instance's mode."""
-        if not self.sink:
-            return fn(*args)
-        return _spans.timed(self.sink, name, fn, *args, step=step,
-                            block=self.blocking)
+        """Run ``fn(*args)`` inside a span named `name` (see spans.timed)."""
+        return _spans.timed(self.sink, name, fn, *args, step=step)
 
     def span(self, name: str, step: Optional[int] = None):
-        """Context manager: host wall-clock span around the block."""
+        """Context manager: span `name` around the block."""
         return _spans.span(self.sink, name, step=step)
+
+    def step(self, step: int):
+        """Context manager: the profiler's step annotation around one
+        iteration of the trainer's loop."""
+        return _spans.step_annotation(step)
 
     def counter(self, name: str, value, step: Optional[int] = None) -> None:
         """Emit one counter sample."""

@@ -126,13 +126,15 @@ def test_null_sink_is_inert(tmp_path):
     tel = Telemetry.null()
     assert not tel
     assert tel is Telemetry.null()     # shared instance
-    assert tel.timed("x", lambda a: a + 1, 1) == 2   # bypasses spans
+    assert tel.timed("x", lambda a: a + 1, 1) == 2   # annotates only
     with tel.span("y"):
         pass
     assert not tel.due(0)              # never due: nothing to emit into
 
 
-def test_span_context_and_timed_block(tmp_path):
+def test_span_context_and_timed(tmp_path):
+    """Both span forms write one record each into a truthy sink; `timed`
+    returns the call's result without waiting on it."""
     from repro.telemetry import EventSink
     from repro.telemetry.events import read_events
     from repro.telemetry.spans import span, timed
@@ -142,13 +144,82 @@ def test_span_context_and_timed_block(tmp_path):
     with span(sink, "serve.tick", step=4):
         time.sleep(0.01)
     out = timed(sink, "master.dispatch", jnp.square, jnp.float32(3.0),
-                step=5, block=True)
+                step=5)
     assert float(out) == 9.0
     sink.close()
     recs = [r for r in read_events(p) if r["kind"] == "span"]
-    assert recs[0]["name"] == "serve.tick" and recs[0]["step"] == 4
+    assert [r["name"] for r in recs] == ["serve.tick", "master.dispatch"]
+    assert recs[0]["step"] == 4 and recs[1]["step"] == 5
     assert recs[0]["dur_s"] >= 0.01
-    assert recs[1]["name"] == "master.dispatch" and recs[1]["dur_s"] > 0
+    assert recs[1]["dur_s"] > 0
+
+
+def test_null_sink_spans_reach_the_profiler(tmp_path):
+    """With nothing to write into, spans still annotate: each lands in a
+    profiler trace's host plane under its own name, with its step."""
+    from jax.profiler import ProfileData
+    from repro.telemetry import Telemetry
+
+    tel = Telemetry.null()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tel.step(7):
+            assert tel.timed("train.dispatch", jnp.square,
+                             jnp.float32(2.0), step=7) is not None
+            with tel.span("train.callback", step=7):
+                pass
+        with tel.span("store.publish"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = [os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+               for f in fs if f.endswith(".xplane.pb")]
+    host = next(p for p in ProfileData.from_file(path).planes
+                if p.name == "/host:CPU")
+    got = {}
+    for line in host.lines:
+        for e in line.events:
+            got[e.name] = {k: v for k, v in e.stats}
+    assert got["train.dispatch"]["step"] == 7
+    assert got["train.callback"]["step"] == 7
+    assert got["train"]["step_num"] == 7
+    assert "store.publish" in got
+
+
+def test_span_names_are_the_taxonomy():
+    """Every span name a call site passes is listed once in the taxonomy
+    of telemetry/spans.py, which the benchmark's readers match on."""
+    import re
+
+    from repro.telemetry import spans
+
+    src = os.path.join(REPO, "src", "repro")
+    used = set()
+    for d, _, files in os.walk(src):
+        for f in files:
+            if f.endswith(".py"):
+                with open(os.path.join(d, f)) as fh:
+                    used |= set(re.findall(
+                        r"\.(?:span|timed)\(\s*\"([a-z_]+\.[a-z_]+)\"",
+                        fh.read()))
+    assert {"train.dispatch", "train.callback", "train.log_sync",
+            "train.probe", "scoring.dispatch", "master.dispatch"} <= used
+    for name in used:
+        assert spans.__doc__.count(f"``{name}``") == 1, name
+
+
+def test_step_phases_are_named_scopes():
+    """Each phase of the fused train step carries its `issgd.*` scope in
+    the lowered program's op metadata (the names XProf shows per op)."""
+    from repro.core.issgd import init_train_state, make_train_step
+
+    pel, scorer, opt, tcfg, params, train = _setup()
+    state = init_train_state(params, opt, train.size, seed=0)
+    step = make_train_step(pel, scorer, opt, tcfg, train.size)
+    text = jax.jit(step).lower(state, train.arrays).as_text(debug_info=True)
+    for phase in ("score", "proposal", "sample", "update", "push",
+                  "monitors"):
+        assert f"/issgd.{phase}/" in text, phase
 
 
 # ---------------------------------------------------------------------------
