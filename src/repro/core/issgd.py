@@ -116,14 +116,15 @@ class StepMetrics(NamedTuple):
 def init_train_state(params, optimizer: Optimizer, num_examples: int,
                      seed: int = 0, table_dtype: str = "f32",
                      index_chunk_size: int = 0) -> TrainState:
-    """Fresh TrainState: stale params start as a copy of θ₀, the store
-    unscored (uniform proposal until the first sweep).  ``table_dtype``/
-    ``index_chunk_size`` select the store representation (see
-    ``weight_store.init_store``)."""
+    """Fresh TrainState: stale params start as a copy of θ₀ in buffers
+    of their own (the trainer donates the params, and keeps θ_stale), the
+    store unscored (uniform proposal until the first sweep).
+    ``table_dtype``/``index_chunk_size`` select the store representation
+    (see ``weight_store.init_store``)."""
     return TrainState(
         params=params,
         opt_state=optimizer.init(params),
-        stale_params=jax.tree.map(lambda x: x, params),
+        stale_params=jax.tree.map(jnp.copy, params),
         store=init_store(num_examples, table_dtype=table_dtype,
                          chunk_size=index_chunk_size),
         step=jnp.zeros((), jnp.int32),

@@ -98,6 +98,26 @@ def _profile_options() -> "jax.profiler.ProfileOptions":
     return opts
 
 
+def _jit_donating_state(raw_step):
+    """``jax.jit(raw_step)`` for a fused step ``raw_step(state, *rest)``
+    that donates every buffer of ``state`` except ``stale_params``: XLA
+    writes the new params, optimizer state, store, step and rng into the
+    buffers they replace, so the runtime allocates fresh outputs only for
+    θ_stale and the step's metrics.  θ_stale is the workers' snapshot
+    between pushes, so it stays the caller's to keep (``main``'s
+    ``on_step``).  The executable keeps ``raw_step``'s name."""
+    def run(state, stale, *rest):
+        return raw_step(state._replace(stale_params=stale), *rest)
+
+    run.__name__ = run.__qualname__ = raw_step.__name__
+    jitted = jax.jit(run, donate_argnums=0)
+
+    def step(state, *rest):
+        return jitted(state._replace(stale_params=None), state.stale_params,
+                      *rest)
+    return step
+
+
 def build_mlp(args, model_axes=()):
     from repro.configs.mlp_svhn import CONFIG, smoke
     from repro.models.mlp import (init_mlp_classifier, mlp_specs,
@@ -245,7 +265,12 @@ docs/ARCHITECTURE.md):
 def main(argv=None, on_step=None):
     """Parse ``argv`` (default ``sys.argv[1:]``), train, and return the
     final ``(state, data)``; ``on_step(i, state, metrics)``, when given,
-    sees every step's outputs (``chip_smoke.py`` drives the trainer so)."""
+    sees every step's outputs (``chip_smoke.py`` drives the trainer so).
+
+    The fused step donates its input state, all but ``stale_params``: the
+    arrays of a step's ``state`` other than ``stale_params`` are valid
+    until the next step is dispatched, so a caller that keeps one must
+    copy it; ``stale_params`` arrays are the caller's to keep."""
     ap = argparse.ArgumentParser(
         epilog=_FLAG_RULES,
         formatter_class=argparse.RawDescriptionHelpFormatter)
@@ -643,7 +668,7 @@ def main(argv=None, on_step=None):
             gated=args.adaptive_is, **pspec_kw)
         step_monitors = raw_step.with_monitors  # jax.jit drops attributes
         step_gated = raw_step.gated
-        step = jax.jit(raw_step)
+        step = _jit_donating_state(raw_step)
         if args.mode == "fused":
             probe = jax.jit(dist.make_sharded_score_step(
                 scorer, tcfg, train.size, mesh, data, optimizer=opt,
@@ -655,7 +680,7 @@ def main(argv=None, on_step=None):
                                    gated=args.adaptive_is)
         step_monitors = raw_step.with_monitors  # jax.jit drops attributes
         step_gated = raw_step.gated
-        step = jax.jit(raw_step)
+        step = _jit_donating_state(raw_step)
         if args.mode == "fused":
             from repro.core.issgd import make_score_step
             probe = jax.jit(make_score_step(scorer, tcfg, train.size))
