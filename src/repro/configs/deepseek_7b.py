@@ -1,5 +1,6 @@
 """deepseek-7b [dense] — 30L d_model=4096 32H (MHA kv=32) d_ff=11008
-vocab=102400, llama-arch.  [arXiv:2401.02954]"""
+vocab=102400, RMSNorm eps 1e-6, llama-arch.  [arXiv:2401.02954 and the
+published deepseek-ai/deepseek-llm-7b-base config.json]"""
 import dataclasses
 
 from repro.models.config import ModelConfig
@@ -13,6 +14,7 @@ CONFIG = ModelConfig(
     num_kv_heads=32,
     d_ff=11008,
     vocab_size=102400,
+    norm_eps=1e-6,
 )
 
 

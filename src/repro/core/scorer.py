@@ -161,6 +161,35 @@ def ghost_sq_norms(
     return psum(sq, tuple(model_axes)), losses
 
 
+def ghost_tap_paths(cfg, seq: int) -> dict:
+    """``{"gram": n, "direct": n}``: how many of the LM ghost scorer's
+    sequence-shared taps `ghost_norm` sends to the Gram kernel and how many
+    to the direct einsum at `seq` positions (`ops.uses_gram`, on this
+    backend).  The taps are `tap_structure`'s, each with the width of its
+    recorded input, walked as `ghost_sq_norms` walks them; rank-1 (Prop. 1)
+    and score taps are in neither count.  Unsharded shapes."""
+    from repro.models.transformer import (init_transformer, per_example_loss,
+                                          tap_structure)
+    taps = tap_structure(cfg, 1, seq)
+    params = jax.eval_shape(lambda: init_transformer(jax.random.key(0), cfg))
+    tokens = jax.ShapeDtypeStruct((1, seq + 1), jnp.int32)
+    _, aux = jax.eval_shape(
+        lambda p, t, tok: per_example_loss(p, cfg, {"tokens": tok}, taps=t,
+                                           collect=True),
+        params, taps, tokens)
+    out = {"gram": 0, "direct": 0}
+    for name, x in aux.records.items():
+        if name not in taps or name.endswith(".qkv_scores"):
+            continue
+        if name == "unembed" and x.ndim == 2:
+            continue
+        # batch 1: the positions are the second-last axis, also for a
+        # token-flattened (P, B*S, d) record
+        gram = ops.uses_gram(x.shape[-2], x.shape[-1], taps[name].shape[-1])
+        out["gram" if gram else "direct"] += 1
+    return out
+
+
 # ----------------------------------------------------------- LM strategies
 def make_lm_scorer(cfg, strategy: str, ssm_mode: str = "ref",
                    model_axes: tuple[str, ...] = (),

@@ -68,19 +68,26 @@ def direct_cost(s: int, din: int, dout: int) -> float:
     return float(s) * din * dout
 
 
+def uses_gram(s: int, din: int, dout: int) -> bool:
+    """Whether `ghost_norm` takes the Gram kernel for a tap of `s`
+    positions, `din` inputs and `dout` outputs: when its FLOPs are at most
+    the direct path's (a tie goes to Gram).  The FLOP model targets the
+    TPU; in interpret mode (CPU validation) the kernel is Python-emulated,
+    so the choice is never Gram there."""
+    return (not _interpret()
+            and ghost_cost(s, din, dout) <= direct_cost(s, din, dout))
+
+
 @functools.partial(jax.jit, static_argnames=("symmetric", "force"))
 def ghost_norm(x, d, symmetric: bool = True, force: str | None = None):
     """||X_nᵀD_n||²_F per example, x:(B,S,din) d:(B,S,dout) → f32[B].
 
-    Picks the cheaper of the Gram kernel and the direct einsum unless
-    `force` in {"gram", "direct"} pins the path.
+    Takes the path `uses_gram` picks unless `force` in {"gram", "direct"}
+    pins it.
     """
     _, s, din = x.shape
     dout = d.shape[2]
-    # the FLOP model targets TPU; in interpret mode (CPU validation) the
-    # Gram kernel is Python-emulated, so auto-select never picks it there
-    use_gram = (not _interpret()
-                and ghost_cost(s, din, dout) <= direct_cost(s, din, dout))
+    use_gram = uses_gram(s, din, dout)
     if force == "gram":
         use_gram = True
     elif force == "direct":

@@ -47,9 +47,11 @@ RMSNorm segments (disable with --no-sequence-parallel):
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
 import time
+import typing
 
 
 def _force_host_devices(n: int) -> None:
@@ -75,7 +77,8 @@ import jax.numpy as jnp
 
 from repro.core.importance import ISConfig
 from repro.core.issgd import ISSGDConfig, init_train_state, make_train_step
-from repro.core.scorer import make_lm_scorer, make_mlp_scorer
+from repro.core.scorer import (ghost_tap_paths, make_lm_scorer,
+                               make_mlp_scorer)
 from repro.core.strategies import PROPOSALS, make_proposal
 from repro.data import make_svhn_like, make_token_dataset
 from repro.launch.compile_cache import enable_compile_cache
@@ -118,11 +121,58 @@ def _jit_donating_state(raw_step):
     return step
 
 
+def _parse_field(kind, key: str, text: str):
+    """`text` as a value of the config field `key`, declared `kind`."""
+    if typing.get_origin(kind) is typing.Literal:
+        if text not in typing.get_args(kind):
+            raise ValueError(f"{key} must be one of "
+                             f"{list(typing.get_args(kind))}, got {text!r}")
+        return text
+    if kind is bool:
+        if text.lower() not in ("true", "false", "1", "0"):
+            raise ValueError(f"{key} is a bool (true/false), got {text!r}")
+        return text.lower() in ("true", "1")
+    try:
+        return kind(text)
+    except ValueError:
+        raise ValueError(f"{key} is {kind.__name__}, got {text!r}") from None
+
+
+def resolve_config(args):
+    """The configuration the flags name: ``--arch`` (its smoke variant
+    with ``--smoke``), then each ``--config-override KEY=VALUE`` replacing
+    one field of the transformer's ``ModelConfig`` by `dataclasses.replace`,
+    the value parsed by the field's declared type.  The one place the
+    trainer turns flags into a configuration.  Raises ValueError for an
+    unknown key, a value of the wrong type, or overrides of the MLP."""
+    overrides = args.config_override
+    if args.arch == "mlp_svhn":
+        if overrides:
+            raise ValueError("--config-override applies to the transformer "
+                             "archs' ModelConfig, not to --arch mlp_svhn")
+        from repro.configs.mlp_svhn import CONFIG, smoke
+        return smoke() if args.smoke else CONFIG
+    from repro.configs import get_config, get_smoke_config
+    from repro.models.config import ModelConfig
+    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    kinds = typing.get_type_hints(ModelConfig)     # field -> declared type
+    changes = {}
+    for item in overrides:
+        key, sep, text = item.partition("=")
+        if not sep:
+            raise ValueError(f"--config-override takes KEY=VALUE, got "
+                             f"{item!r}")
+        if key not in kinds:
+            raise ValueError(f"--config-override: ModelConfig has no field "
+                             f"{key!r}")
+        changes[key] = _parse_field(kinds[key], key, text)
+    return dataclasses.replace(cfg, **changes) if changes else cfg
+
+
 def build_mlp(args, model_axes=()):
-    from repro.configs.mlp_svhn import CONFIG, smoke
     from repro.models.mlp import (init_mlp_classifier, mlp_specs,
                                   per_example_loss)
-    cfg = smoke() if args.smoke else CONFIG
+    cfg = resolve_config(args)
     train, _ = make_svhn_like(jax.random.key(args.seed), n=args.examples,
                               dim=cfg.input_dim)
     params = init_mlp_classifier(jax.random.key(args.seed + 1), cfg)
@@ -132,11 +182,14 @@ def build_mlp(args, model_axes=()):
     return params, train, pel, scorer, mlp_specs(cfg)
 
 
-def build_lm(args, model_axes=(), seq_shard=False):
-    from repro.configs import get_config, get_smoke_config
+def build_lm(args, tel, model_axes=(), seq_shard=False):
+    """The transformer's data, weights, loss and scorer.  With the ghost
+    scorer, `tel` gets the counters ``scorer.gram_taps`` and
+    ``scorer.direct_taps``: how many of its sequence-shared taps take the
+    Gram kernel and how many the direct einsum (`ghost_tap_paths`)."""
     from repro.models.transformer import (init_transformer, per_example_loss,
                                           transformer_specs)
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    cfg = resolve_config(args)
     train = make_token_dataset(jax.random.key(args.seed), n=args.examples,
                                seq=args.seq + 1, vocab=cfg.vocab_size)
     params = init_transformer(jax.random.key(args.seed + 1), cfg)
@@ -144,6 +197,10 @@ def build_lm(args, model_axes=(), seq_shard=False):
                                         seq_shard=seq_shard)[0]
     scorer = make_proposal(make_lm_scorer, cfg, _proposal_name(args),
                            model_axes=model_axes, seq_shard=seq_shard)
+    if _proposal_name(args) == "ghost":
+        paths = ghost_tap_paths(cfg, args.seq)
+        tel.counter("scorer.gram_taps", paths["gram"])
+        tel.counter("scorer.direct_taps", paths["direct"])
     return params, train, pel, scorer, transformer_specs(cfg)
 
 
@@ -161,6 +218,7 @@ def validate_flags(ap, args, mp: int) -> None:
       * --stream excludes --mode exact (the oracle rescores the resident
         dataset each step).
       * --strategy full is a single-device test oracle: no --model-parallel.
+      * --config-override names fields of a transformer arch's ModelConfig.
     """
     if args.async_scoring and args.mode not in ("relaxed", "uniform"):
         ap.error("--async-scoring requires --mode relaxed|uniform (fused "
@@ -200,6 +258,10 @@ def validate_flags(ap, args, mp: int) -> None:
         ap.error(f"--index-chunk-size {args.index_chunk_size} must divide "
                  f"the per-shard rows "
                  f"({args.examples // max(args.mesh, 1)})")
+    try:
+        cfg = resolve_config(args)
+    except ValueError as e:
+        ap.error(str(e))
     if mp <= 1:
         return
     if _proposal_name(args) == "full":
@@ -207,8 +269,6 @@ def validate_flags(ap, args, mp: int) -> None:
                  "not support --model-parallel; use ghost or ghost_rev")
     if args.arch == "mlp_svhn":
         return  # uneven hidden dims fall back to replication with a warning
-    from repro.configs import get_config, get_smoke_config
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     has_attn = any(s.mixer == "attn" for s in cfg.layer_specs())
     has_ssm = any(s.mixer == "mamba" for s in cfg.layer_specs())
     if has_attn and cfg.num_heads % mp:
@@ -259,6 +319,8 @@ docs/ARCHITECTURE.md):
   --score-ttl K       composes with everything (per-chunk decay of stale
                       scores toward the uniform floor; 0 = off, the
                       HLO-identical default)
+  --config-override   transformer archs only; KEY must be a ModelConfig
+                      field, VALUE parses by its type (after --smoke)
 """
 
 
@@ -276,6 +338,12 @@ def main(argv=None, on_step=None):
         formatter_class=argparse.RawDescriptionHelpFormatter)
     ap.add_argument("--arch", default="mlp_svhn")
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--config-override", action="append", default=[],
+                    metavar="KEY=VALUE",
+                    help="replace one field of the transformer arch's "
+                    "ModelConfig (after --smoke), parsed by the field's "
+                    "type; repeatable, e.g. --config-override "
+                    "num_layers=2 --config-override dtype=float32")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=64)
     ap.add_argument("--score-batch", type=int, default=256)
@@ -489,23 +557,19 @@ def main(argv=None, on_step=None):
         params, train, pel, scorer, param_specs = build_mlp(args, model_axes)
     else:
         params, train, pel, scorer, param_specs = build_lm(
-            args, model_axes, seq_shard=seq_shard)
+            args, tel, model_axes, seq_shard=seq_shard)
     pspec_kw = (dict(param_specs=param_specs, params_template=params)
                 if mp > 1 else {})
 
     fused_score = None
     if args.mode == "fused":
+        _cfg = resolve_config(args)
         if args.arch == "mlp_svhn":
-            from repro.configs.mlp_svhn import CONFIG, smoke
             from repro.models.mlp import per_example_loss_and_score
-            _cfg = smoke() if args.smoke else CONFIG
             fused_score = lambda p, b: per_example_loss_and_score(
                 p, b, _cfg, model_axes=model_axes)
         else:
-            from repro.configs import get_config, get_smoke_config
             from repro.models.transformer import per_example_loss_and_score
-            _cfg = (get_smoke_config(args.arch) if args.smoke
-                    else get_config(args.arch))
             fused_score = lambda p, b: per_example_loss_and_score(
                 p, _cfg, b, model_axes=model_axes, seq_shard=seq_shard)
 
@@ -598,11 +662,9 @@ def main(argv=None, on_step=None):
         if args.mode == "fused":
             probe = pipe.probe
         if args.serve_loop:
-            from repro.configs import get_config, get_smoke_config
             from repro.serving import (ContinuousBatcher, ServeLoop,
                                        TrafficIngest, make_synthetic_traffic)
-            scfg = (get_smoke_config(args.arch) if args.smoke
-                    else get_config(args.arch))
+            scfg = resolve_config(args)
             serve_max_len = args.serve_prompt_len + args.serve_max_new
             b_pp = None
             if mp > 1:

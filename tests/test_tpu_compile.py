@@ -136,6 +136,69 @@ def test_mlp_svhn_relaxed_ghost_step_compiles(one_chip, mosaic):
     assert total < V5E_HBM_BYTES, mem
 
 
+# the deepseek_7b.seq2048 cell: 2 layers x 4 scored sequences of 2,048
+# positions, so each layer tap's launch holds 8 rows
+DEEPSEEK_CELL = ["--arch", "deepseek_7b", "--config-override", "num_layers=2",
+                 "--config-override", "vocab_size=12800",
+                 "--config-override", "dtype=float32"]
+
+
+@pytest.mark.parametrize("din,dout", [(4096, 4096), (4096, 11008)],
+                         ids=["attention", "feed_forward"])
+def test_ghost_norm_compiles_at_the_deepseek_cell_shapes(one_chip, mosaic,
+                                                         din, dout):
+    """The cell's layer taps: 4096 -> 4096 (wq, wk, wv, wo: the FLOP
+    model's tie, which goes to Gram) and 4096 -> 11008 (w_in, w_gate)."""
+    rows, s = 8, 2048
+    assert ops.uses_gram(s, din, dout)
+    c = ops.ghost_norm.lower(_spec(one_chip, (rows, s, din)),
+                             _spec(one_chip, (rows, s, dout))).compile()
+    assert _kernel_calls(c) == 1
+
+
+def test_deepseek_cell_step_compiles(one_chip, mosaic):
+    """The cell's whole training step as the trainer builds it from the
+    cell's flags (relaxed, ghost scorer, batch 2, score batch 4, 4,096
+    rows of 2,049 tokens, the state donated): one Gram ghost-norm launch
+    per tap, 8, and the program fits one chip's HBM."""
+    from repro.core.issgd import ISSGDConfig, init_train_state, make_train_step
+    from repro.core.scorer import make_lm_scorer
+    from repro.launch import train
+    from repro.models.transformer import init_transformer, per_example_loss
+    from repro.optim import sgd
+
+    ap = train.argparse.ArgumentParser()
+    ap.add_argument("--arch")
+    ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--config-override", action="append", default=[])
+    cfg = train.resolve_config(ap.parse_args(DEEPSEEK_CELL))
+    n, s = 4096, 2048
+    opt = sgd(0.01)
+    step = make_train_step(
+        lambda p, b: per_example_loss(p, cfg, b)[0],
+        make_lm_scorer(cfg, "ghost"), opt,
+        ISSGDConfig(batch_size=2, score_batch_size=4, mode="relaxed"), n)
+    state = jax.eval_shape(lambda: init_train_state(
+        init_transformer(jax.random.key(1), cfg), opt, n))
+    place = lambda t: jax.tree.map(
+        lambda a: _spec(one_chip, a.shape, a.dtype), t)
+    state = place(state)
+    data = {"tokens": _spec(one_chip, (n, s + 1), jnp.int32)}
+
+    def run(state, stale, data):     # train._jit_donating_state's program
+        return step(state._replace(stale_params=stale), data)
+
+    c = jax.jit(run, donate_argnums=0).lower(
+        state._replace(stale_params=None), state.stale_params, data).compile()
+    assert _kernel_calls(c) == 8
+    mem = c.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+             + mem.generated_code_size_in_bytes)
+    assert mem.alias_size_in_bytes > 4 * cfg.param_count() * 0.99
+    assert total < V5E_HBM_BYTES, mem
+
+
 # ------------------------------------------- kernels Mosaic still refuses
 _TILING = ("ValueError: The Pallas TPU lowering currently requires that the "
            "last two dimensions of your block shape are divisible by 8 and "

@@ -61,7 +61,8 @@ _HELD = """
             held["read"] = jax.tree.map(np.asarray, held["theta0"])
 
     state, _ = train.main(argv + ["--steps", "{steps}"], on_step=on_step)
-    args = train.argparse.Namespace(smoke=True, examples=256, seed=3,
+    args = train.argparse.Namespace(arch="mlp_svhn", config_override=[],
+                                    smoke=True, examples=256, seed=3,
                                     strategy="ghost", proposal_strategy="")
     theta0 = train.build_mlp(args)[0]
     for a, b in zip(jax.tree.leaves(held["read"]), jax.tree.leaves(theta0)):
